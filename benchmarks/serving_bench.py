@@ -53,6 +53,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.serving import (CapacityBudget, Engine, FCFSScheduler,
                            Telemetry, aggregate_metrics, make_backend,
@@ -440,6 +441,7 @@ def main(argv=None):
     ap.add_argument("--no-json", action="store_true",
                     help="skip appending to the BENCH json trajectory")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=not args.full).replace(
         param_dtype="float32", compute_dtype="float32", remat="none",

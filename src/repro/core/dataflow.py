@@ -59,9 +59,8 @@ def lower_single_layer_hlo(cfg: ModelConfig, mesh, batch: int = 4,
     elif one.family == "audio":
         specs = {"frames": jax.ShapeDtypeStruct(
             (batch, seq, one.frontend.frontend_dim), jnp.float32)}
-    with mesh:
-        p_sds, _ = model.abstract_params()
-        p_sh = model.param_shardings(rules)
-        lowered = jax.jit(model.forward, in_shardings=(p_sh, None)) \
-            .lower(p_sds, specs)
-        return lowered.compile().as_text()
+    p_sds, _ = model.abstract_params()
+    p_sh = model.param_shardings(rules)
+    lowered = jax.jit(model.forward, in_shardings=(p_sh, None)) \
+        .lower(p_sds, specs)
+    return lowered.compile().as_text()

@@ -21,7 +21,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
+
+def _block(n: int, want: int, unit: int) -> int:
+    """``want`` when it divides ``n``, else the largest multiple of
+    ``unit`` below it that does; ``n`` itself when ``n <= want`` or no
+    such multiple exists. Each is a legal TPU block: a multiple of the
+    (8, 128) tile, or the whole dimension (d_ff = 8960 = 128 * 70 takes
+    256, not 512)."""
+    if n <= want or n % want == 0:
+        return min(n, want)
+    for b in range(want - want % unit, 0, -unit):
+        if n % b == 0:
+            return b
+    return n
 
 
 def _act(h, kind: str):
@@ -77,9 +89,8 @@ def ffn_act(x: jax.Array, w_up: jax.Array, w_gate: jax.Array | None,
     F = w_up.shape[1]
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    block_m = min(block_m, M)
-    block_f = min(block_f, F)
-    assert M % block_m == 0 and F % block_f == 0, (M, F, block_m, block_f)
+    block_m = _block(M, block_m, 8)
+    block_f = _block(F, block_f, 128)
     num_m, num_f = M // block_m, F // block_f
     gated = w_gate is not None
     wg = w_gate if gated else w_up  # dummy ref when ungated (never read)
@@ -98,7 +109,7 @@ def ffn_act(x: jax.Array, w_up: jax.Array, w_gate: jax.Array | None,
         out_specs=pl.BlockSpec((block_m, D), lambda mi, fi: (mi, 0)),
         out_shape=jax.ShapeDtypeStruct((M, D), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, D), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, w_up, wg, w_down)
@@ -156,9 +167,8 @@ def ffn_act_int8(x: jax.Array, w_up_q: jax.Array, w_up_scale: jax.Array,
     F = w_up_q.shape[1]
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    block_m = min(block_m, M)
-    block_f = min(block_f, F)
-    assert M % block_m == 0 and F % block_f == 0
+    block_m = _block(M, block_m, 8)
+    block_f = _block(F, block_f, 128)
     num_m, num_f = M // block_m, F // block_f
     kernel = functools.partial(_ffn_q_kernel, kind=kind, num_f=num_f)
     return pl.pallas_call(
@@ -174,7 +184,7 @@ def ffn_act_int8(x: jax.Array, w_up_q: jax.Array, w_up_scale: jax.Array,
         out_specs=pl.BlockSpec((block_m, D), lambda mi, fi: (mi, 0)),
         out_shape=jax.ShapeDtypeStruct((M, D), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, D), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, w_up_q, w_up_scale.reshape(1, F), w_down_q,

@@ -11,8 +11,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _norm_kernel(x_ref, s_ref, b_ref, o_ref, *, kind: str, eps: float,
                  use_bias: bool):
@@ -57,7 +55,7 @@ def fused_norm(x: jax.Array, scale: jax.Array, bias: jax.Array | None,
         ],
         out_specs=pl.BlockSpec((block_m, D), lambda mi: (mi, 0)),
         out_shape=jax.ShapeDtypeStruct((M, D), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, scale.reshape(1, D), bb)

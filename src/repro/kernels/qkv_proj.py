@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _qkv_kernel(x_ref, w_ref, b_ref, o_ref, *, use_bias: bool):
     x = x_ref[...].astype(jnp.float32)
@@ -59,7 +57,7 @@ def qkv_proj(x: jax.Array, w: jax.Array, b: jax.Array | None = None, *,
         out_specs=pl.BlockSpec((block_m, block_n),
                                lambda mi, ni: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x, w, bb)

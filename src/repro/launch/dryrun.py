@@ -59,46 +59,45 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     specs = input_specs(cfg, shape)
     b_sh = batch_shardings(cfg, shape, rules, specs)
 
-    with mesh:
-        if shape.kind == "train":
-            opt_cfg = AdamWConfig(
-                state_dtype="bfloat16" if cfg.param_dtype == "bfloat16"
-                else "float32")
-            step = make_train_step(
-                model, opt_cfg,
-                microbatches=(mb_override if mb_override is not None
-                              else train_microbatches(arch, shape)),
-                grad_accum_dtype=grad_accum_dtype(arch))
-            state_sds = abstract_train_state(model, opt_cfg)
-            state_sh = train_state_shardings(model, rules, opt_cfg)
-            lowered = jax.jit(
-                step,
-                in_shardings=(state_sh, b_sh),
-                out_shardings=(state_sh, None),
-                donate_argnums=(0,),
-            ).lower(state_sds, specs)
-        elif shape.kind == "prefill":
-            step = make_prefill_step(model, max_len=shape.seq_len)
-            p_sds, _ = model.abstract_params()
-            p_sh = model.param_shardings(rules)
-            lowered = jax.jit(
-                step, in_shardings=(p_sh, b_sh),
-            ).lower(p_sds, specs)
-        else:  # decode
-            step = make_serve_step(model)
-            p_sds, _ = model.abstract_params()
-            p_sh = model.param_shardings(rules)
-            c_sds, _ = model.cache_spec(shape.global_batch, shape.seq_len)
-            c_sh = cache_shardings(model, rules, shape.global_batch,
-                                   shape.seq_len)
-            pos_sds = jax.ShapeDtypeStruct((), jnp.int32)
-            lowered = jax.jit(
-                step,
-                in_shardings=(p_sh, b_sh["tokens"], c_sh, replicated(rules)),
-                out_shardings=(None, c_sh),
-                donate_argnums=(2,),
-            ).lower(p_sds, specs["tokens"], c_sds, pos_sds)
-        compiled = lowered.compile()
+    if shape.kind == "train":
+        opt_cfg = AdamWConfig(
+            state_dtype="bfloat16" if cfg.param_dtype == "bfloat16"
+            else "float32")
+        step = make_train_step(
+            model, opt_cfg,
+            microbatches=(mb_override if mb_override is not None
+                          else train_microbatches(arch, shape)),
+            grad_accum_dtype=grad_accum_dtype(arch))
+        state_sds = abstract_train_state(model, opt_cfg)
+        state_sh = train_state_shardings(model, rules, opt_cfg)
+        lowered = jax.jit(
+            step,
+            in_shardings=(state_sh, b_sh),
+            out_shardings=(state_sh, None),
+            donate_argnums=(0,),
+        ).lower(state_sds, specs)
+    elif shape.kind == "prefill":
+        step = make_prefill_step(model, max_len=shape.seq_len)
+        p_sds, _ = model.abstract_params()
+        p_sh = model.param_shardings(rules)
+        lowered = jax.jit(
+            step, in_shardings=(p_sh, b_sh),
+        ).lower(p_sds, specs)
+    else:  # decode
+        step = make_serve_step(model)
+        p_sds, _ = model.abstract_params()
+        p_sh = model.param_shardings(rules)
+        c_sds, _ = model.cache_spec(shape.global_batch, shape.seq_len)
+        c_sh = cache_shardings(model, rules, shape.global_batch,
+                               shape.seq_len)
+        pos_sds = jax.ShapeDtypeStruct((), jnp.int32)
+        lowered = jax.jit(
+            step,
+            in_shardings=(p_sh, b_sh["tokens"], c_sh, replicated(rules)),
+            out_shardings=(None, c_sh),
+            donate_argnums=(2,),
+        ).lower(p_sds, specs["tokens"], c_sds, pos_sds)
+    compiled = lowered.compile()
     return lowered, compiled, model, {"cfg": cfg, "shape": shape,
                                       "mesh": mesh}
 

@@ -84,6 +84,8 @@ def generate(model: Model, params, batch: dict, prompt_len: int,
 
 
 def main(argv=None):
+    """Serve a synthetic stream; returns (finished requests, backend)."""
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import get_mesh
     from repro.serving import (Engine, Telemetry, aggregate_metrics,
                                make_backend, make_synthetic_requests,
@@ -196,12 +198,19 @@ def main(argv=None):
                          "telemetry snapshot every N engine steps "
                          "(0 = only at exit) — enables telemetry")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced).replace(
-        param_dtype="float32", compute_dtype="float32", remat="none",
-        kv_policy=args.kv_policy, kv_hot_window=args.hot_window)
+        remat="none", kv_policy=args.kv_policy,
+        kv_hot_window=args.hot_window)
+    if args.reduced:
+        # the reduced configs' parity tests hold float32 token streams;
+        # a full-width config serves in its own (bf16) dtypes
+        cfg = cfg.replace(param_dtype="float32", compute_dtype="float32")
     model = Model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    # one compiled program: eager init compiles every op on its own and
+    # keeps each float32 draw alive beside its cast
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
     # VQA requests occupy cfg.frontend.num_tokens visual positions on top
     # of (at least one) text token — size the pool for the larger of the
     # two request shapes
@@ -330,7 +339,7 @@ def main(argv=None):
                   f"{'EXACT' if drift == 0.0 else f'drift {drift:.3g} J'})")
         tel.close()
     print("[serve] sample token ids:", done[0].generated[:12])
-    return done
+    return done, backend
 
 
 if __name__ == "__main__":

@@ -53,42 +53,41 @@ def main(argv=None):
     pipe = SyntheticPipeline(cfg, DataConfig(args.batch, args.seq,
                                              seed=args.seed))
 
-    with mesh:
-        params = model.init(jax.random.PRNGKey(args.seed))
-        state = adamw_init(params, opt_cfg)
-        step_fn = jax.jit(
-            make_train_step(model, opt_cfg, args.microbatches),
-            in_shardings=(train_state_shardings(model, rules, opt_cfg),
-                          None),
-            donate_argnums=(0,))
+    params = model.init(jax.random.PRNGKey(args.seed))
+    state = adamw_init(params, opt_cfg)
+    step_fn = jax.jit(
+        make_train_step(model, opt_cfg, args.microbatches),
+        in_shardings=(train_state_shardings(model, rules, opt_cfg),
+                      None),
+        donate_argnums=(0,))
 
-        ckpt = CheckpointManager(args.ckpt_dir, keep=2)
-        loop = FaultTolerantLoop(
-            step_fn, ckpt,
-            FaultPolicy(checkpoint_every=args.ckpt_every))
-        state, start = loop.resume_or_init(state)
-        if start:
-            print(f"[train] resumed from checkpoint at step {start}")
+    ckpt = CheckpointManager(args.ckpt_dir, keep=2)
+    loop = FaultTolerantLoop(
+        step_fn, ckpt,
+        FaultPolicy(checkpoint_every=args.ckpt_every))
+    state, start = loop.resume_or_init(state)
+    if start:
+        print(f"[train] resumed from checkpoint at step {start}")
 
-        losses = []
+    losses = []
 
-        def on_metrics(step, metrics):
-            loss = float(metrics["loss"])
-            losses.append(loss)
-            if step % 10 == 0 or step == start:
-                print(f"[train] step {step:5d} loss {loss:.4f} "
-                      f"gnorm {float(metrics['grad_norm']):.3f} "
-                      f"lr {float(metrics['lr']):.2e}")
+    def on_metrics(step, metrics):
+        loss = float(metrics["loss"])
+        losses.append(loss)
+        if step % 10 == 0 or step == start:
+            print(f"[train] step {step:5d} loss {loss:.4f} "
+                  f"gnorm {float(metrics['grad_norm']):.3f} "
+                  f"lr {float(metrics['lr']):.2e}")
 
-        t0 = time.time()
-        state, end = loop.run(state, pipe.host_slice, start, args.steps,
-                              on_metrics)
-        dt = time.time() - t0
-        tput = args.steps * args.batch * args.seq / max(dt, 1e-9)
-        print(f"[train] {args.steps} steps in {dt:.1f}s "
-              f"({tput:,.0f} tok/s), loss {losses[0]:.4f} -> "
-              f"{losses[-1]:.4f}")
-        return losses
+    t0 = time.time()
+    state, end = loop.run(state, pipe.host_slice, start, args.steps,
+                          on_metrics)
+    dt = time.time() - t0
+    tput = args.steps * args.batch * args.seq / max(dt, 1e-9)
+    print(f"[train] {args.steps} steps in {dt:.1f}s "
+          f"({tput:,.0f} tok/s), loss {losses[0]:.4f} -> "
+          f"{losses[-1]:.4f}")
+    return losses
 
 
 if __name__ == "__main__":

@@ -20,7 +20,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.quant import compress_grad, decompress_grad, grad_scale
@@ -48,8 +47,8 @@ def compressed_pod_allreduce(grads, mesh: Mesh):
             return decompress_grad(qsum, shared, gl.dtype) / npods
 
         spec = P(*([None] * g.ndim))
-        return shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec,
-                         check_rep=False)(g)
+        return jax.shard_map(body, mesh=mesh, in_specs=spec,
+                             out_specs=spec, check_vma=False)(g)
 
     return jax.tree.map(one, grads)
 

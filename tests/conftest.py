@@ -96,12 +96,13 @@ def make_mesh():
     import jax
 
     from repro.launch.mesh import make_local_mesh
+    from repro.launch.mesh import make_mesh as _auto_mesh
 
     n = jax.device_count()
     if n == 1:
         return make_local_mesh()
     m = 2 if n % 2 == 0 else 1
-    return jax.make_mesh((n // m, m), ("data", "model"))
+    return _auto_mesh((n // m, m), ("data", "model"))
 
 
 def forced_device_env(n: int = 8) -> dict:
@@ -111,7 +112,7 @@ def forced_device_env(n: int = 8) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count={n}")
-    env["JAX_PLATFORM_NAME"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = (str(REPO / "src")
                          + os.pathsep + env.get("PYTHONPATH", ""))
     return env

@@ -19,8 +19,9 @@ import json
 import jax
 from repro.configs.base import get_config
 from repro.core.dataflow import count_collectives, lower_single_layer_hlo
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 8), ("data", "model"))
+mesh = make_mesh((2, 8), ("data", "model"))
 out = {}
 for arch in ("granite-3-2b", "rwkv6-7b"):
     cfg = get_config(arch, reduced=True).replace(

@@ -150,8 +150,10 @@ def test_ffn_act_matches_ref(kind, gated, dtype):
 
 
 def test_ffn_shapes_sweep():
+    # the last case asks for blocks that divide neither dim: the kernel
+    # takes bm 24 and bf 128 (as d_ff 8960 takes 256 of a requested 512)
     for M, D, F, bm, bf in [(64, 32, 128, 32, 32), (256, 128, 512, 128, 256),
-                            (128, 96, 192, 64, 96)]:
+                            (128, 96, 192, 64, 96), (48, 32, 384, 32, 256)]:
         ks = jax.random.split(jax.random.PRNGKey(M + F), 3)
         x = jax.random.normal(ks[0], (M, D), jnp.float32)
         w1 = jax.random.normal(ks[1], (D, F), jnp.float32) * 0.1

@@ -324,3 +324,9 @@ def test_paged_decode_vmem_budget():
     cold = 2 * 128 * 128 * (1 + 4)
     scales = 2 * 128 * (4 + 4)
     assert n >= cold + scales
+    # a block holds every kv head of its tokens (fastvlm-1.7b: 2 heads,
+    # G = 6): the int8 tiles grow with the heads, the f32 casts do not
+    n2 = PD.paged_decode_vmem_bytes(block_k=128, G=6, D=128, hot_w=128,
+                                    hkv=2)
+    assert n2 < V5E_VMEM // 4
+    assert n2 >= 2 * 128 * 128 * (2 + 4) + 2 * 128 * (2 * 4 + 4)
