@@ -65,10 +65,11 @@ import jax.numpy as jnp
 from repro.core import kv_tiers as KT
 from repro.models import Model
 from repro.models.counting import streamed_unit_indices, weight_stream_split
-from repro.serving.kv_pool import (KVPoolState, TieredKVPool, batch_axes,
-                                   map_spill_stores, slot_kv_bytes,
-                                   spill_lane_bytes, tree_expand,
-                                   tree_squeeze)
+from repro.serving.kv_pool import (KVByteRates, KVPoolState, TieredKVPool,
+                                   batch_axes, map_spill_stores,
+                                   slot_kv_bytes, spill_lane_bytes,
+                                   tree_expand, tree_squeeze)
+from repro.serving.telemetry import span, tracing
 from repro.sharding import ShardingRules
 
 
@@ -236,6 +237,17 @@ class InferenceBackend(Protocol):
         ...
 
 
+def _launch(program, args: tuple, *scalars):
+    """``program(*args, *scalars)``, the scalars copied to the device as
+    int32 first: the copies inside the span
+    ``repro.backend.<program's name>.put``, the call inside ``.launch``."""
+    name = "repro.backend." + program.__name__
+    with span(name + ".put"):
+        scalars = [jnp.asarray(s, jnp.int32) for s in scalars]
+    with span(name + ".launch"):
+        return program(*args, *scalars)
+
+
 class _JittedBackend:
     """Shared scaffolding: validates the config, derives the slot-axis
     tree, and builds the three jitted programs (step / prefill / insert).
@@ -353,6 +365,13 @@ class _JittedBackend:
             for p, _ in jax.tree_util.tree_flatten_with_path(ext_shapes)[0])
         self._zero_slot = None
         self._zero_ext = None
+        # the KV pool's counters on the decode launch's span: every slot
+        # at max_len, and the active slots at their positions
+        self._kv_rates = KVByteRates.of(model, max_len)
+        self.kv_pool_bytes = num_slots * sum(self._kv_rates.split(max_len))
+        # each jitted program's function name is its XLA module's name
+        # (`jit_step`, `jit_ext_part`, ...), which the benchmark's trace
+        # readers select by, and names its spans (`_launch`)
         self._step = jax.jit(self._build_step())
         self._insert = jax.jit(self._build_insert())
         self._ext_part = jax.jit(self._build_extend(commit=False))
@@ -620,22 +639,35 @@ class _JittedBackend:
     def extend_step(self, batch: dict, state: KVPoolState, ext: dict,
                     slot, pos, length, commit: bool
                     ) -> tuple[jax.Array | None, dict | None, KVPoolState]:
-        pos = jnp.asarray(pos, jnp.int32)
-        length = jnp.asarray(length, jnp.int32)
         if not commit:
-            new_ext = self._ext_part(self.params, batch, ext, pos, length)
+            new_ext = _launch(self._ext_part, (self.params, batch, ext),
+                              pos, length)
             return None, new_ext, state
-        tok, cache = self._ext_commit(
-            self.params, batch, state.cache, ext,
-            jnp.asarray(slot, jnp.int32), pos, length)
+        tok, cache = _launch(self._ext_commit,
+                             (self.params, batch, state.cache, ext),
+                             slot, pos, length)
         return tok, None, dataclasses.replace(state, cache=cache)
 
     def decode_step(self, toks, state: KVPoolState, pos, active
                     ) -> tuple[jax.Array, KVPoolState]:
-        ntoks, cache = self._step(
-            self.params, jnp.asarray(toks), state.cache,
-            jnp.asarray(pos), jnp.asarray(active))
+        name = "repro.backend." + self._step.__name__
+        with span(name + ".put"):
+            toks_d = jnp.asarray(toks)
+            pos_d = jnp.asarray(pos)
+            active_d = jnp.asarray(active)
+        with span(name + ".launch", **self._kv_args(pos, active)):
+            ntoks, cache = self._step(self.params, toks_d, state.cache,
+                                      pos_d, active_d)
         return ntoks, dataclasses.replace(state, cache=cache)
+
+    def _kv_args(self, pos, active) -> dict:
+        """``kv_pool_bytes`` and ``kv_live_bytes`` for the decode launch's
+        span, only while the profiler records, from the engine's host
+        arrays ``pos`` and ``active``."""
+        if not tracing():
+            return {}
+        return {"kv_pool_bytes": self.kv_pool_bytes,
+                "kv_live_bytes": self._kv_rates.live(pos, active)}
 
     def evict_slot(self, state: KVPoolState, slot, lane, length
                    ) -> KVPoolState:
@@ -653,10 +685,9 @@ class _JittedBackend:
                                                   self.max_len),
                 spill_axes=(self._spill_axes if self.spill_compress
                             else None))
-        spill, writes = self._evict(
-            state.cache, state.spill, state.spill_writes,
-            jnp.asarray(slot, jnp.int32), jnp.asarray(lane, jnp.int32),
-            jnp.asarray(length, jnp.int32))
+        spill, writes = _launch(
+            self._evict, (state.cache, state.spill, state.spill_writes),
+            slot, lane, length)
         return dataclasses.replace(state, spill=spill, spill_writes=writes)
 
     def restore_slot(self, state: KVPoolState, lane, slot
@@ -664,17 +695,15 @@ class _JittedBackend:
         if state.spill is None:
             raise ValueError("nothing has been spilled; there is no "
                              "lane to restore from")
-        cache = self._restore(state.cache, state.spill,
-                              jnp.asarray(lane, jnp.int32),
-                              jnp.asarray(slot, jnp.int32))
+        cache = _launch(self._restore, (state.cache, state.spill),
+                        lane, slot)
         return dataclasses.replace(state, cache=cache)
 
     def _insert_state(self, state: KVPoolState, req_cache: dict, slot
                      ) -> KVPoolState:
         """Scatter a batch-1 cache into slot ``slot`` (pool internals:
         recycling scrubs; not part of the serving step surface)."""
-        cache = self._insert(state.cache, req_cache,
-                             jnp.asarray(slot, jnp.int32))
+        cache = _launch(self._insert, (state.cache, req_cache), slot)
         return dataclasses.replace(state, cache=cache)
 
     # ---- paged prefix block store (PR 7) -----------------------------
@@ -707,31 +736,25 @@ class _JittedBackend:
         """Write workspace rows [pos, pos+block_tokens) of ``ext`` into
         block ``bid`` — the ONE physical write a shared block ever
         takes."""
-        store = self._pfx_save_ws(state.prefix, ext,
-                                  jnp.asarray(bid, jnp.int32),
-                                  jnp.asarray(pos, jnp.int32))
+        store = _launch(self._pfx_save_ws, (state.prefix, ext), bid, pos)
         return dataclasses.replace(state, prefix=store)
 
     def prefix_load_ws(self, state: KVPoolState, ext: dict, bid, pos
                        ) -> dict:
         """Seed block ``bid``'s rows into ``ext`` at position ``pos``
         (admission gather of one hit block)."""
-        return self._pfx_load_ws(ext, state.prefix,
-                                 jnp.asarray(bid, jnp.int32),
-                                 jnp.asarray(pos, jnp.int32))
+        return _launch(self._pfx_load_ws, (ext, state.prefix), bid, pos)
 
     def prefix_save_state(self, state: KVPoolState, ext: dict, bid
                           ) -> KVPoolState:
         """Snapshot ``ext``'s recurrent states into block ``bid`` (the
         chain-terminal resume point for SSM architectures)."""
-        store = self._pfx_save_state(state.prefix, ext,
-                                     jnp.asarray(bid, jnp.int32))
+        store = _launch(self._pfx_save_state, (state.prefix, ext), bid)
         return dataclasses.replace(state, prefix=store)
 
     def prefix_load_state(self, state: KVPoolState, ext: dict, bid
                           ) -> dict:
-        return self._pfx_load_state(ext, state.prefix,
-                                    jnp.asarray(bid, jnp.int32))
+        return _launch(self._pfx_load_state, (ext, state.prefix), bid)
 
 
 class LocalBackend(_JittedBackend):

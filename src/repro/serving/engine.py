@@ -59,7 +59,7 @@ from repro.serving.block_pool import (BlockPool, PrefixHit,
 from repro.serving.request import FINISHED, PREEMPTED, RUNNING, Request
 from repro.serving.scheduler import (CapacityBudget, FCFSScheduler,
                                      PrefillChunk)
-from repro.serving.telemetry import NullTelemetry
+from repro.serving.telemetry import NullTelemetry, span
 from repro.simulator.hardware import CHIME
 
 
@@ -546,7 +546,9 @@ class Engine:
             return []
         if want_register:
             self._register_prefix(fl, full_ws)
-        return self._commit(fl, int(tok))
+        with span("repro.engine.fetch"):
+            tok = int(tok)
+        return self._commit(fl, tok)
 
     def _adopt_prefix(self, ch: PrefillChunk):
         """Seed the freshly-admitted prefill from its probed prefix-cache
@@ -730,6 +732,7 @@ class Engine:
             self.block_pool.begin_epoch()
             self._probed.clear()
         tel.phase_begin("plan")
+        decode_slots = int(self._active.sum())
         kwargs = {}
         if self._plan_preemptive:
             kwargs = dict(
@@ -738,30 +741,38 @@ class Engine:
                 free_lanes=self.pool.free_lanes)
         plan = self.scheduler.plan(
             active_slots=self.pool.active_slots,
-            decode_slots=int(self._active.sum()),
+            decode_slots=decode_slots,
             free_slots=self.pool.free_slots,
             inflight=None if fl is None else (fl.req, fl.pos),
             chunk_unit=self.backend.chunk_unit, **kwargs)
         evictions = tuple(getattr(plan, "evictions", ()))
         offloads = tuple(getattr(plan, "offloads", ()))
         restores = tuple(getattr(plan, "restores", ()))
-        tel.phase_end(chunks=len(plan.chunks))
-        tel.phase_begin("evict")
-        for req in evictions:
-            self._evict(req)
-        tel.phase_end(count=len(evictions))
-        tel.phase_begin("idle-offload")
-        for req in offloads:
-            self._evict(req, offload=True)
-        tel.phase_end(count=len(offloads))
-        tel.phase_begin("restore")
-        for req in restores:
-            self._restore(req)
-        tel.phase_end(count=len(restores))
-        tel.phase_begin("chunk-prefill")
-        for ch in plan.chunks:
-            events.extend(self._run_chunk(ch))
-        tel.phase_end(count=len(plan.chunks))
+        tel.phase_end(chunks=len(plan.chunks), decode_slots=decode_slots)
+        # a phase opens only when the plan has entries for it
+        if evictions:
+            tel.phase_begin("evict")
+            for req in evictions:
+                self._evict(req)
+            tel.phase_end(count=len(evictions))
+        if offloads:
+            tel.phase_begin("idle-offload")
+            for req in offloads:
+                self._evict(req, offload=True)
+            tel.phase_end(count=len(offloads))
+        if restores:
+            tel.phase_begin("restore")
+            for req in restores:
+                self._restore(req)
+            tel.phase_end(count=len(restores))
+        if plan.chunks:
+            tel.phase_begin("chunk-prefill")
+            for ch in plan.chunks:
+                with span("repro.engine.chunk", rid=ch.req.rid,
+                          start=ch.start, length=ch.length,
+                          commit=int(ch.commit)):
+                    events.extend(self._run_chunk(ch))
+            tel.phase_end(count=len(plan.chunks))
         self.stats["steps"] += 1
         # plan.decode is the planner's say (a custom planner may dedicate
         # a step to prefill); _active is the physical guard
@@ -771,26 +782,27 @@ class Engine:
         tel.phase_begin("decode")
         ntoks, self.pool.state = self.backend.decode_step(
             self._tok, self.pool.state, self._pos, self._active)
-        ntoks = np.asarray(ntoks)
+        with span("repro.engine.fetch"):
+            ntoks = np.asarray(ntoks)
         self.stats["decode_steps"] += 1
-        n_emitted = 0
-        for slot in np.nonzero(self._active)[0]:
-            req = self._slot_req[slot]
-            tok = int(ntoks[slot])
-            req.emit(tok)
-            req.token_times.append(self.clock())
-            tel.token(req)
-            req.resident_steps += 1
-            self._pos[slot] += 1
-            self._slot_total_len[slot] += 1
-            self._tok[slot, 0] = tok
-            self.stats["decode_tokens"] += 1
-            n_emitted += 1
-            done = req.finished_by(tok)
-            events.append((req.rid, tok, done))
-            if done:
-                self._retire(int(slot))
-        tel.phase_end(count=n_emitted)
+        slots = np.nonzero(self._active)[0]
+        with span("repro.engine.emit", tokens=len(slots)):
+            for slot in slots:
+                req = self._slot_req[slot]
+                tok = int(ntoks[slot])
+                req.emit(tok)
+                req.token_times.append(self.clock())
+                tel.token(req)
+                req.resident_steps += 1
+                self._pos[slot] += 1
+                self._slot_total_len[slot] += 1
+                self._tok[slot, 0] = tok
+                self.stats["decode_tokens"] += 1
+                done = req.finished_by(tok)
+                events.append((req.rid, tok, done))
+                if done:
+                    self._retire(int(slot))
+        tel.phase_end(count=len(slots))
         tel.step_end(self._gauges() if tel.enabled else None)
         return events
 

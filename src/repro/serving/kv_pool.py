@@ -27,6 +27,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import kv_tiers as KT
 from repro.models.counting import kv_elems_per_token, kv_scale_elems_per_token
@@ -167,6 +168,54 @@ def _charged_len(max_len: int, length: int | None,
     return min(-(-length // bt) * bt, max_len)
 
 
+@dataclasses.dataclass(frozen=True)
+class KVByteRates:
+    """One slot's KV bytes as per-token terms: ``hot`` bytes per position
+    up to ``hot_cap`` positions (the hot ring; every position of a flat
+    cache), ``cold`` bytes per position (the int8 cold tier and its
+    scales) and ``state`` bytes whatever the length (recurrent states).
+    `slot_kv_bytes` prices a charged length with them; `live` counts the
+    positions the slots hold."""
+    hot: int
+    hot_cap: int
+    cold: int
+    state: int
+
+    @classmethod
+    def of(cls, model, max_len: int) -> KVByteRates:
+        cfg = model.cfg
+        cd = jnp.dtype(cfg.compute_dtype).itemsize
+        seq_elems = kv_elems_per_token(cfg)
+        shapes, _ = model.cache_spec(1, max_len)
+        state = 0
+        for path, leaf in jax.tree_util.tree_flatten_with_path(shapes)[0]:
+            key = path[-1].key if hasattr(path[-1], "key") \
+                else str(path[-1])
+            if key in _STORE_KEYS:
+                continue
+            nbytes = jnp.dtype(leaf.dtype).itemsize
+            for d in leaf.shape:
+                nbytes *= d
+            state += nbytes
+        if cfg.kv_policy == "tiered":
+            cold = (seq_elems * jnp.dtype(jnp.int8).itemsize
+                    + kv_scale_elems_per_token(cfg)
+                    * jnp.dtype(jnp.float32).itemsize)
+            return cls(seq_elems * cd, cfg.kv_hot_window, int(cold), state)
+        return cls(seq_elems * cd, max_len, 0, state)
+
+    def split(self, length: int) -> tuple[int, int]:
+        """(hot, cold) bytes of one slot holding ``length`` positions."""
+        return (self.hot * min(self.hot_cap, length) + self.state,
+                self.cold * length)
+
+    def live(self, pos: np.ndarray, active: np.ndarray) -> int:
+        """Bytes the ``active`` slots hold at positions ``pos``."""
+        p = pos[active].astype(np.int64)
+        return int(self.hot * np.minimum(p, self.hot_cap).sum()
+                   + self.cold * p.sum() + self.state * p.size)
+
+
 def slot_kv_bytes(model, max_len: int, *, length: int | None = None,
                   block_tokens: int | None = None) -> tuple[int, int]:
     """(dram_hot_bytes, rram_cold_bytes) of ONE slot's cache.
@@ -184,29 +233,8 @@ def slot_kv_bytes(model, max_len: int, *, length: int | None = None,
     admission gate and the capacity bench use so their math agrees with
     what paging actually allocates.
     """
-    cfg = model.cfg
-    cd = jnp.dtype(cfg.compute_dtype).itemsize
-    seq_elems = kv_elems_per_token(cfg)
-    L = _charged_len(max_len, length, block_tokens)
-    shapes, _ = model.cache_spec(1, max_len)
-    state_bytes = 0
-    for path, leaf in jax.tree_util.tree_flatten_with_path(shapes)[0]:
-        key = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
-        if key in _STORE_KEYS:
-            continue
-        nbytes = jnp.dtype(leaf.dtype).itemsize
-        for d in leaf.shape:
-            nbytes *= d
-        state_bytes += nbytes
-    if cfg.kv_policy == "tiered":
-        W = min(cfg.kv_hot_window, L)
-        hot = seq_elems * W * cd + state_bytes
-        cold = (seq_elems * L * jnp.dtype(jnp.int8).itemsize
-                + kv_scale_elems_per_token(cfg) * L
-                * jnp.dtype(jnp.float32).itemsize)
-    else:
-        hot = seq_elems * L * cd + state_bytes
-        cold = 0
+    rates = KVByteRates.of(model, max_len)
+    hot, cold = rates.split(_charged_len(max_len, length, block_tokens))
     return int(hot), int(cold)
 
 

@@ -30,21 +30,30 @@ and scheduler:
    (`prometheus()`) and an optional JSONL snapshot stream.
 
 Telemetry is strictly opt-in: `Engine(telemetry=None)` (the default)
-installs `NullTelemetry`, whose hooks are empty methods — the disabled
-path costs a handful of no-op calls per multi-millisecond step (<2%
-throughput, asserted by the bench and tests). No jax import here; the
-module is pure host-side bookkeeping.
+installs `NullTelemetry`, whose hooks keep no records — the disabled
+path costs a handful of calls per multi-millisecond step (<2%
+throughput, asserted by the tests).
+
+Both hubs also put the engine's step and phases on the profiler's own
+clock: `repro.engine.step` (a `jax.profiler.StepTraceAnnotation`) and
+`repro.engine.<phase>` annotations, which the engine and backend nest
+further `span`s in (`repro.engine.chunk`, `repro.backend.step.launch`,
+...). While `jax.profiler` records, they land in its trace beside the
+device's operations, so a device-idle gap can be named by the host work
+around it; otherwise each costs a fraction of a microsecond.
 """
 
 from __future__ import annotations
 
 import collections
+import contextlib
 import json
 import math
 import re
 import time
 
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.simulator.chime_sim import (cost_layers, decode_token_terms,
                                        prefill_terms, prefix_adopt_terms,
@@ -85,6 +94,53 @@ REASON_CODES = {
     "prefix_adopt": "admitted request seeded its prefill from cached "
                     "prefix blocks (skipped recompute of the hit span)",
 }
+
+
+_OFF = contextlib.nullcontext()
+
+
+def span(name: str, **args):
+    """A span ``name`` (``repro.<layer>.<what>``) on the profiler's clock,
+    with ``args`` as its event's stats; while no trace records, a no-op
+    context (a fraction of a microsecond)."""
+    if TraceAnnotation.is_enabled():
+        return TraceAnnotation(name, **args)
+    return _OFF
+
+
+def tracing() -> bool:
+    """True while the profiler records: span arguments that take work to
+    compute are computed only then."""
+    return TraceAnnotation.is_enabled()
+
+
+class _EngineSpans:
+    """The engine's step and phase spans as profiler annotations, opened
+    and closed by the hubs' step/phase hooks (the engine's one thread
+    nests them). While no trace records, each is a None on the stack."""
+
+    def __init__(self):
+        self._open: list[TraceAnnotation | None] = []
+
+    def _enter(self, ann: TraceAnnotation | None):
+        if ann is not None:
+            ann.__enter__()
+        self._open.append(ann)
+
+    def _exit(self, args: dict | None = None):
+        ann = self._open.pop()
+        if ann is not None:
+            if args:
+                ann.set_metadata(**args)
+            ann.__exit__(None, None, None)
+
+    def _step_span(self, step: int):
+        self._enter(StepTraceAnnotation("repro.engine.step", step_num=step)
+                    if TraceAnnotation.is_enabled() else None)
+
+    def _phase_span(self, name: str):
+        self._enter(TraceAnnotation("repro.engine." + name)
+                    if TraceAnnotation.is_enabled() else None)
 
 
 # ---------------------------------------------------------------------------
@@ -269,7 +325,7 @@ class TierLedger:
 # ---------------------------------------------------------------------------
 # the telemetry hub
 # ---------------------------------------------------------------------------
-class Telemetry:
+class Telemetry(_EngineSpans):
     """Live serving observability hub (see module docstring).
 
     Construct with no arguments and hand to ``Engine(telemetry=...)`` —
@@ -289,6 +345,7 @@ class Telemetry:
                  fused_decode: bool | None = None,
                  sparse_read_tau: float | None = None,
                  weight_stream: bool | None = None):
+        super().__init__()
         self.cfg = cfg
         self.platform = platform
         self.spill_compressed = spill_compressed
@@ -395,6 +452,7 @@ class Telemetry:
 
     # -- step framing / phases -----------------------------------------
     def step_begin(self, step: int):
+        self._step_span(step)
         self._step = step
         self.counters["steps"] += 1
         if self.ledger is not None:
@@ -417,22 +475,22 @@ class Telemetry:
             self.ledger.step_end()
         if self.stats_every and (self._step + 1) % self.stats_every == 0:
             self.snapshot()
+        self._exit()
 
     def phase_begin(self, name: str):
+        self._phase_span(name)
         self._phase_stack.append((name, self._now()))
 
     def phase_end(self, count: int | None = None, **args):
-        """Close the innermost phase. ``count=0`` elides the span (an
-        empty evict/restore phase every step would bury the timeline);
-        any other value lands in the span args."""
+        """Close the innermost phase; ``count`` and ``args`` land in the
+        span's args."""
         name, t0 = self._phase_stack.pop()
         t1 = self._now()
-        if count == 0:
-            return
         self.phase_s[name] = self.phase_s.get(name, 0.0) + (t1 - t0)
         if count is not None:
             args["count"] = count
         self._span(PID_ENGINE, 0, name, t0, t1, args or None)
+        self._exit(args)
 
     # -- request lifecycle ---------------------------------------------
     def _req_segment(self, rid: int, state: str | None, t: float):
@@ -750,11 +808,12 @@ class Telemetry:
         return out
 
 
-class NullTelemetry:
-    """Disabled-telemetry stand-in: every hook is an empty method, so
-    the engine's instrumented hot path costs a handful of no-op calls
-    per step (<2% throughput — the contract the bench asserts).
-    `Engine(telemetry=None)` installs this."""
+class NullTelemetry(_EngineSpans):
+    """Disabled-telemetry stand-in: it keeps no records, so the engine's
+    instrumented hot path costs a handful of calls per step (<2%
+    throughput — the contract the tests assert); only the step and phase
+    hooks open their profiler spans. `Engine(telemetry=None)` installs
+    this."""
 
     enabled = False
     ledger = None
@@ -763,16 +822,18 @@ class NullTelemetry:
         pass
 
     def step_begin(self, step):
-        pass
+        self._step_span(step)
 
     def step_end(self, gauges=None):
-        pass
+        self._exit()
 
     def phase_begin(self, name):
-        pass
+        self._phase_span(name)
 
     def phase_end(self, count=None, **args):
-        pass
+        if count is not None:
+            args["count"] = count
+        self._exit(args)
 
     def request_submitted(self, req):
         pass

@@ -156,6 +156,30 @@ def test_chrome_trace_mid_run_closes_open_segments():
     assert len(eng.finished) == 2
 
 
+def test_phases_open_only_for_plan_entries():
+    """The engine opens evict / idle-offload / restore / chunk-prefill
+    only when the step's plan has entries for them, so an unpressured
+    run records none of the first three; a phase the engine does open is
+    recorded whatever its count."""
+    cfg, model, params = build_model(ARCH)
+    tel = Telemetry()
+    eng = Engine(LocalBackend(model, params, 2, 24), telemetry=tel)
+    eng.run(make_requests(cfg, [(8, 4), (6, 3)], seed=4))
+    info = validate_chrome_trace(tel.chrome_trace())
+    assert set(info["phases"]) == {"plan", "chunk-prefill", "commit",
+                                   "decode"}
+    spans = [e for e in tel.chrome_trace()["traceEvents"]
+             if e.get("ph") == "X" and e["pid"] == 1]
+    prefills = [e for e in spans if e["name"] == "chunk-prefill"]
+    assert prefills and all(e["args"]["count"] >= 1 for e in prefills)
+    plans = [e for e in spans if e["name"] == "plan"]
+    assert len(plans) == eng.stats["steps"]
+    assert all("decode_slots" in e["args"] for e in plans)
+    tel.phase_begin("evict")
+    tel.phase_end(count=0)
+    assert "evict" in tel.phase_s
+
+
 def test_validate_chrome_trace_rejects_garbage():
     with pytest.raises(ValueError):
         validate_chrome_trace({"events": []})
